@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import api, compat
+from repro import api
 from repro.core.stepsize import PowerSchedule
 from repro.distributed import ring
 from repro.launch.mesh import make_mc_mesh
@@ -32,7 +32,7 @@ print(f"devices: {jax.device_count()}, mesh: {mesh}")
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
 w = jnp.asarray(rng.normal(size=(32, 48)), jnp.float32)
-ag = jax.jit(compat.shard_map(
+ag = jax.jit(jax.shard_map(
     lambda xb, wl: ring.ring_ag_matmul(xb, wl, "workers"), mesh=mesh,
     in_specs=(P("workers", None), P(None, "workers")),
     out_specs=P(None, "workers")))

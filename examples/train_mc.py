@@ -21,6 +21,7 @@ import numpy as np
 from repro import api
 from repro.checkpoint import AsyncCheckpointer, restore_checkpoint
 from repro.core.stepsize import PowerSchedule
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -42,6 +43,7 @@ def main():
     args = ap.parse_args()
     if args.ckpt_every < 1:
         ap.error("--ckpt-every must be >= 1")
+    enable_compile_cache()
 
     # scale users linearly and keep Netflix's ~37 ratings/user so the
     # problem stays well-determined at laptop scale

@@ -79,7 +79,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import partition as part
 from .schedule import OwnershipSchedule
 from .stepsize import PowerSchedule
-from ..compat import shard_map as _shard_map
 from ..kernels import ops as kops
 from ..kernels import ref as kref
 from ..kernels.policy import KernelPolicy
@@ -164,6 +163,12 @@ def _stream_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
     kernels own their inner loop, so their fused driver keeps the
     step-scan epoch (``entry`` is unused here but keeps the driver
     signature uniform).
+
+    ``data`` holds the ``(slots, p)`` stream arrays flattened to 1-D,
+    and each slot is a dynamic slice of ``p`` entries: a ``(slots, p)``
+    array fed to the scan as ``xs`` would be tiled with its ``p``-wide
+    minor dimension padded to 128 lanes on TPU (16x the bytes at p=8,
+    past the 16 GB of one v5e at the Netflix shape).
     """
     rows, cols, vals, mask = data
     p, m_local, k = Ws.shape
@@ -181,16 +186,16 @@ def _stream_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
             functools.partial(kref.sgd_pair, compute_dtype=cd),
             in_axes=(0, 0, 0, None, None))
 
-    def slot(carry, x):
+    def slot(t, carry):
         Wf, Hf = carry
-        r, c, v, m = x
+        r, c, v, m = (jax.lax.dynamic_slice_in_dim(a, t * p, p)
+                      for a in (rows, cols, vals, mask))
         w_new, h_new = pair(Wf[r], Hf[c], v, lr, lam)
         Wf = Wf.at[jnp.where(m, r, P)].set(w_new, mode="drop")
         Hf = Hf.at[jnp.where(m, c, Q)].set(h_new, mode="drop")
-        return (Wf, Hf), ()
+        return Wf, Hf
 
-    (Wf, Hf), _ = jax.lax.scan(slot, (Wf, Hf),
-                               (rows, cols, vals, mask))
+    Wf, Hf = jax.lax.fori_loop(0, rows.shape[0] // p, slot, (Wf, Hf))
     return Wf.reshape(p, m_local, k), Hf.reshape(p, n_local, k)
 
 
@@ -447,7 +452,7 @@ class NomadRingEngine:
             fn = _spmd_epoch_fn(br.p, axis, self.lam, self.policy,
                                 br.sub_starts, self.sched)
             pspec = P(axis)
-            epoch_shard = _shard_map(
+            epoch_shard = jax.shard_map(
                 fn, mesh=self.mesh,
                 in_specs=(pspec, pspec, pspec, pspec, pspec, pspec, P()),
                 out_specs=(pspec, pspec))
@@ -739,8 +744,9 @@ class NomadRingEngine:
             if self.mesh is None:
                 if self.policy.impl in _STREAM_IMPLS:
                     if self._stream is None:
-                        self._stream = tuple(map(
-                            jnp.asarray, part.epoch_stream(self.br)))
+                        self._stream = tuple(
+                            jnp.asarray(a.reshape(-1))
+                            for a in part.epoch_stream(self.br))
                     self.Ws, self.Hs, tr, ok = _local_train_stream(
                         self.Ws, self.Hs, self._stream, lrs, rec_pos,
                         self.lam, ridx, cidx, tvals, policy=self.policy,

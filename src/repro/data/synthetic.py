@@ -42,9 +42,22 @@ def synthetic_ratings(m: int, n: int, nnz: int, k: int = 16, *, seed: int = 0,
     # §5.5: factors ~ N(0, I_k); ratings get N(0, noise) noise
     W = rng.standard_normal((m, k)) / np.sqrt(k)
     H = rng.standard_normal((n, k)) / np.sqrt(k)
-    vals = np.sum(W[rows] * H[cols], axis=-1) + noise * rng.standard_normal(
+    vals = _row_dots(W, H, rows, cols) + noise * rng.standard_normal(
         len(rows))
     return rows, cols, vals.astype(np.float64), W, H
+
+
+def _row_dots(W, H, rows, cols, chunk: int = 1 << 16) -> np.ndarray:
+    """``np.sum(W[rows] * H[cols], axis=-1)`` in row chunks, so the
+    ``(nnz, k)`` float64 intermediate never exists whole (79 GB at the
+    full Netflix shape with k=100).  Each output is the same per-row
+    reduction as the unchunked expression, so the values are bitwise
+    the same."""
+    out = np.empty(len(rows), dtype=np.float64)
+    for lo in range(0, len(rows), chunk):
+        hi = lo + chunk
+        out[lo:hi] = np.sum(W[rows[lo:hi]] * H[cols[lo:hi]], axis=-1)
+    return out
 
 
 def netflix_like(scale: float = 1e-4, *, seed: int = 0, k: int = 16):

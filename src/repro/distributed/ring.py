@@ -23,8 +23,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .. import compat
-
 
 def ring_ag_matmul(x_block, w_local, axis_name: str):
     """Per-shard view (use under shard_map).
@@ -33,7 +31,7 @@ def ring_ag_matmul(x_block, w_local, axis_name: str):
     ``axis_name``).  w_local: (d, f_loc) — this shard's columns of W.
     Returns y: (m_loc * p, f_loc) = X_full @ w_local, row-ordered.
     """
-    p = compat.axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % p) for i in range(p)]
 
@@ -58,7 +56,7 @@ def ring_rs_matmul(x_local, w_local, axis_name: str):
     over rows — i.e. reduce_scatter(X @ W) where the contraction dim is
     sharded.  Returns y: (m / p, f) — this shard's row block of the sum.
     """
-    p = compat.axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % p) for i in range(p)]
     m, f = x_local.shape[0], w_local.shape[1]
@@ -75,7 +73,8 @@ def ring_rs_matmul(x_local, w_local, axis_name: str):
         acc = jax.lax.ppermute(acc, axis_name, perm)
         return acc, ()
 
-    acc0 = compat.pvary(jnp.zeros((m_loc, f), partial.dtype), axis_name)
+    acc0 = jax.lax.pcast(jnp.zeros((m_loc, f), partial.dtype), axis_name,
+                         to="varying")
     acc, _ = jax.lax.scan(step, acc0, jnp.arange(p - 1))
     # after p-1 hops the accumulator in hand is destined for our own
     # block; add our local partial last.

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .sharding import ShardingCtx
-from .. import compat
 
 
 def _bspec(B: int, ctx: ShardingCtx):
@@ -42,7 +41,7 @@ def row_parallel_dense(x, w, ctx: ShardingCtx, bias=None):
         part = x_loc @ w_full
         return jax.lax.psum(part.astype(x_loc.dtype), tp)
 
-    y = compat.shard_map(
+    y = jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(bspec, None, tp), P(tp, dp)),
         out_specs=P(bspec, None, None),
@@ -89,7 +88,7 @@ def col_parallel_dense_2dtp(x, w, ctx: ShardingCtx, bias=None):
         return jax.lax.psum_scatter(part.astype(x_loc.dtype), dp,
                                     scatter_dimension=0, tiled=True)
 
-    y = compat.shard_map(
+    y = jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(bspec, None, None), P(dp, tp)),
         out_specs=P(bspec, None, tp),
@@ -125,7 +124,7 @@ def row_parallel_dense_2dtp(x, w, ctx: ShardingCtx, bias=None):
                                       concat_axis=2, tiled=True)
         return jax.lax.all_gather(part, dp, axis=2, tiled=True)
 
-    y = compat.shard_map(
+    y = jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(bspec, None, tp), P(tp, dp)),
         out_specs=P(bspec, None, None),
@@ -158,7 +157,7 @@ def vocab_parallel_embed(table, tokens, ctx: ShardingCtx):
         emb = emb * valid[..., None].astype(emb.dtype)
         return jax.lax.psum(emb, tp)
 
-    return compat.shard_map(
+    return jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(tp, dp), P(bspec, None)),
         out_specs=P(bspec, None, None),

@@ -1,9 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy: the Pallas kernels target TPU; on any other backend they
-run in ``interpret=True`` mode (Python emulation — correct, slow).  The
-XLA fallbacks in :mod:`repro.kernels.ref` are used by the dry-run (Pallas
-does not lower on the CPU backend) and whenever ``impl='xla'``.
+Dispatch policy: ``impl='auto'`` resolves to the XLA update
+(:mod:`repro.kernels.ref`) on every backend — it is the one lowering
+that holds a Netflix-width cell (the Pallas kernels keep whole factor
+tiles in VMEM; see :mod:`.nomad_sgd`).  The Pallas kernels run only when
+chosen; they compile on TPU, run in ``interpret=True`` mode (Python
+emulation — correct, slow) on any other backend, and refuse with a
+``ValueError`` a compiled cell they cannot hold.
 
 Precision threads through here from :class:`KernelPolicy.dtype_policy`:
 ``compute_dtype``/``accum_fp32`` select fp32 accumulation over
@@ -28,8 +31,7 @@ def on_tpu() -> bool:
 
 
 def on_accelerator() -> bool:
-    """True on any accelerator backend (TPU or GPU) — the occupancy grid
-    kernel targets both; CPU keeps the single-program interpret path."""
+    """True on any accelerator backend (TPU or GPU)."""
     return jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
 
 
@@ -70,9 +72,7 @@ def _resolve(policy, impl, chunk, wave_chunk):
     elif isinstance(policy, str):
         policy = KernelPolicy(impl=policy, chunk=chunk,
                               wave_chunk=wave_chunk)
-    name = policy.impl
-    if name == "auto":
-        name = "pallas" if on_tpu() else "xla"
+    name = "xla" if policy.impl == "auto" else policy.impl
     return policy, name
 
 
@@ -101,8 +101,8 @@ def block_sgd_cells(Ws, Hs, rows, cols, vals, mask, lr, lam, *,
     disjoint factor blocks (the generalized-diagonal invariant), so the
     batch axis is free parallelism.
 
-    For ``impl='wave_pallas'`` on an accelerator (or when
-    ``policy.block_rows`` forces it), the whole batch is one
+    For ``impl='wave_pallas'`` on TPU with cells whose factor tiles fit
+    VMEM (or when ``policy.block_rows`` forces it), the whole batch is one
     ``pallas_call`` with grid ``(p, n_chunks)`` —
     :func:`~.nomad_sgd.nomad_sgd_waves_grid` — so occupancy scales with
     the cell count instead of relying on ``vmap``-of-kernel.  Every
@@ -110,7 +110,7 @@ def block_sgd_cells(Ws, Hs, rows, cols, vals, mask, lr, lam, *,
     ``vmap`` over :func:`block_sgd`, which is bitwise-identical.
     """
     if policy.impl == "wave_pallas" and policy.wants_grid(
-            int(Ws.shape[1]), int(Hs.shape[1])):
+            int(Ws.shape[1]), int(Hs.shape[1]), int(Ws.shape[2])):
         return nomad_sgd_waves_grid(
             Ws, Hs, rows, cols, vals, mask, lr, lam,
             wave_chunk=policy.wave_chunk, interpret=not on_tpu(),

@@ -33,13 +33,6 @@ DTYPE_POLICIES: Tuple[str, ...] = ("fp32", "bf16", "fp16")
 #: waves across permute steps and break the serializability proof)
 _WAVE_DOWNGRADE = {"wave": "xla", "wave_pallas": "pallas"}
 
-#: per-backend VMEM/shared-memory budget (bytes) the autotuner sizes the
-#: grid kernel's resident blocks against.  TPU VMEM is ~16 MiB/core and
-#: GPU shared memory ~100-200 KiB/SM, but the Pallas GPU lowering spills
-#: to L2/registers, so a few MiB of "hot set" is the practical target;
-#: CPU (interpret mode) just wants cache-friendly tiles.
-_MEM_BUDGET = {"tpu": 12 << 20, "gpu": 4 << 20, "cpu": 1 << 20}
-
 
 @dataclasses.dataclass(frozen=True)
 class KernelPolicy:
@@ -59,11 +52,11 @@ class KernelPolicy:
                     (DESIGN.md §13); fp32 keeps every path bitwise equal
                     to the historical kernels.
     block_rows   -- occupancy-grid selector for the wave Pallas kernel:
-                    0 = auto (grid over (cell, wave-chunk) on
-                    accelerators, single-program scan on CPU), -1 =
-                    never use the grid kernel, > 0 = use the grid kernel
-                    whenever the per-cell factor blocks fit
-                    (max(m_local, n_local) <= block_rows).
+                    0 = auto (grid over (cell, wave-chunk) on TPU when
+                    the cell's factor tiles fit VMEM, single-program
+                    scan otherwise), -1 = never use the grid kernel,
+                    > 0 = use the grid kernel whenever
+                    max(m_local, n_local) <= block_rows.
     """
     impl: str = "auto"
     chunk: int = 1024
@@ -130,7 +123,7 @@ class KernelPolicy:
         import jax.numpy as jnp
         return jnp.float32
 
-    def wants_grid(self, m_local: int, n_local: int) -> bool:
+    def wants_grid(self, m_local: int, n_local: int, k: int) -> bool:
         """Whether the wave Pallas dispatch should use the occupancy
         grid kernel for cells of this shape (``block_rows`` semantics
         above).  Only meaningful for ``impl='wave_pallas'``."""
@@ -138,41 +131,18 @@ class KernelPolicy:
             return False
         if self.block_rows > 0:
             return max(m_local, n_local) <= self.block_rows
-        from .ops import on_accelerator
-        return on_accelerator()
-
-    def autotune(self, *, m_local: int, n_local: int, k: int,
-                 backend: str | None = None) -> "KernelPolicy":
-        """Pick occupancy knobs for a cell shape on the current (or
-        given) backend: ``wave_chunk`` sized so the resident W/H blocks
-        plus one rating chunk fit the backend's fast-memory budget, and
-        ``block_rows`` pinned so dispatch decisions are explicit in the
-        returned policy.  Pure function of (shape, backend) — safe to
-        call per-pack and cache on the frozen result."""
-        if backend is None:
-            import jax
-            backend = jax.default_backend()
-        budget = _MEM_BUDGET.get(backend, _MEM_BUDGET["cpu"])
-        bytes_per = {"fp32": 4, "bf16": 2, "fp16": 2}[self.dtype_policy]
-        kp = -(-max(k, 1) // 128) * 128          # LANE-padded rank
-        resident = (m_local + n_local) * kp * bytes_per
-        # leftover budget feeds the streamed rating chunk: 3 int32 index
-        # planes + 1 fp32 value plane + bool mask, wave_width <= p-wide
-        wave_bytes = max(1, 16 * max(m_local, n_local) // 8)
-        spare = max(budget - resident, budget // 8)
-        wave_chunk = int(min(64, max(4, spare // max(wave_bytes, 1) // 64)))
-        block_rows = (-1 if backend == "cpu"
-                      else max(m_local, n_local))
-        return dataclasses.replace(
-            self, wave_chunk=wave_chunk, block_rows=block_rows)
+        from .nomad_sgd import fits_vmem
+        from .ops import on_tpu
+        return on_tpu() and fits_vmem(m_local, n_local, k, grid=True)
 
     @property
     def serve_impl(self) -> str:
         """Which serving top-k scorer this policy selects
         (``repro.serve.topk``): the Pallas tile kernel for the Pallas
-        train impls, the XLA scan otherwise; ``'auto'`` follows the
-        train dispatch rule (Pallas on TPU).  The wave/sequential split
-        is a training concern — for serving only the lowering matters."""
+        train impls, the XLA scan otherwise; ``'auto'`` is the Pallas
+        scorer on TPU (where it compiles at catalog widths) and the XLA
+        scan elsewhere.  The wave/sequential split is a training concern
+        — for serving only the lowering matters."""
         if self.impl == "auto":
             from .ops import on_tpu
             return "pallas" if on_tpu() else "xla"

@@ -17,7 +17,6 @@ from typing import Optional  # noqa: E402
 import jax               # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from .. import compat                         # noqa: E402
 from .. import configs                        # noqa: E402
 from ..distributed.sharding import make_ctx   # noqa: E402
 from ..models.config import ModelConfig       # noqa: E402
@@ -110,7 +109,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
                   "temp_size_in_bytes", "generated_code_size_in_bytes",
                   "alias_size_in_bytes")
         if hasattr(mem, k)}
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     rec["cost"] = {k: float(v) for k, v in ca.items()
                    if isinstance(v, (int, float)) and
                    k in ("flops", "bytes accessed", "optimal_seconds",
@@ -181,7 +180,7 @@ def depth_probe(cfg: ModelConfig, shape: dict, mesh, cost_full: dict, *,
         lowered, _ = lower_cell(configs.get_config(cfg_alias(cfg.name)),
                                 shape, mesh, opt_overrides=opt_overrides,
                                 cfg_overrides=ov, train_kwargs=train_kwargs)
-        costs.append(compat.cost_analysis(lowered.compile()))
+        costs.append(lowered.compile().cost_analysis())
     out = {}
     for key in ("flops", "bytes accessed", "transcendentals"):
         c1 = float(costs[0].get(key, 0.0))

@@ -23,7 +23,6 @@ import jax.numpy as jnp       # noqa: E402
 import numpy as np            # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from .. import compat                       # noqa: E402
 from ..configs import nomad_mf              # noqa: E402
 from ..core.nomad import _spmd_epoch_fn     # noqa: E402
 from ..core.partition import sub_block_starts  # noqa: E402
@@ -85,7 +84,7 @@ def run_mc_cell(dataset: str, multi_pod: bool, sub_blocks: int = 1,
     pspec = P("workers")
     # check_vma off: pallas_call has no replication rule under shard_map,
     # and the dry-run only lowers/compiles (no numerics to protect)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         epoch_fn, mesh=mesh,
         in_specs=(pspec, pspec, pspec, pspec, pspec, pspec, P()),
         out_specs=(pspec, pspec), check_vma=False)
@@ -104,7 +103,7 @@ def run_mc_cell(dataset: str, multi_pod: bool, sub_blocks: int = 1,
         for k in ("argument_size_in_bytes", "output_size_in_bytes",
                   "temp_size_in_bytes", "alias_size_in_bytes")
         if hasattr(mem, k)}
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     rec["cost"] = {k: float(v) for k, v in ca.items()
                    if isinstance(v, (int, float)) and
                    k in ("flops", "bytes accessed", "transcendentals")}

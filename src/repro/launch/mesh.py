@@ -17,10 +17,8 @@ ICI_BW = 50e9                   # bytes/s per link
 
 
 def _mk(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):       # jax >= 0.5
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    return jax.make_mesh(shape, axes)           # jax 0.4.x: Auto is default
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -132,7 +132,9 @@ def main():
                  "demo problem)")
 
     from ..serve import FactorStore, RecServer, ServeConfig
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     problem = result = None
     if args.demo:
         problem, result = _train_demo(args)

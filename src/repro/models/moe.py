@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from . import layers
-from .. import compat
 
 
 def moe_init(key, cfg, dtype):
@@ -156,7 +155,7 @@ def moe_apply(p, x, cfg, ctx=None):
         # replicated over dp *by construction* (same inputs, same math on
         # every dp shard after the FSDP all_gather), but the varying-type
         # inference can't prove it through the all_gather.
-        out, aux_loss, dropped = compat.shard_map(
+        out, aux_loss, dropped = jax.shard_map(
             local_fn, mesh=ctx.mesh,
             in_specs=(P(bspec, None, None), P(None, None),
                       P(tp, dp, None), P(tp, dp, None), P(tp, None, dp)),

@@ -17,21 +17,30 @@ both implementations tile the catalog:
   DESIGN.md §5); the running top-k lives in the resident output block
   and is merged in-kernel by an exact iterative (score, id) selection.
 
-Both are **exact** against the dense argsort oracle
-(:func:`topk_dense_oracle`) with deterministic tie-breaking: ties in
-score resolve to the *smaller item id*, always.  The XLA path gets this
-from ``lax.top_k``'s lower-index-first tie rule plus an ordering
-invariant (running entries always carry smaller ids than the current
-tile's candidates, and within each part equal scores appear in
-id-ascending order — so position order inside the merged array *is* id
-order); the Pallas path selects each slot explicitly by
-(max score, then min id).  Exactness incl. engineered ties is
-property-tested in tests/test_serve.py.
+Selection is **exact over the scores the scorer computes**, with
+deterministic tie-breaking: ties in score resolve to the *smaller item
+id*, always.  The XLA path gets this from ``lax.top_k``'s
+lower-index-first tie rule plus an ordering invariant (running entries
+always carry smaller ids than the current tile's candidates, and within
+each part equal scores appear in id-ascending order — so position order
+inside the merged array *is* id order); the Pallas path selects each
+slot explicitly by (max score, then min id).
+
+The scores themselves are f32 dot products whose summation order
+depends on the matmul shape and the backend, so two scorers (or one
+scorer and the dense oracle) may differ in the last bits.  The contract
+against :func:`topk_dense_oracle` (host float64) is therefore: every
+score within the dot product's forward error bound
+``gamma_k * sum_i |w_i h_i|`` of the exact one, and the ids exact except
+where the oracle scores of two items lie within that bound of each
+other.  Integer-valued factors score exactly in any order, so there
+ids, scores and the tie rule are bitwise (property-tested with
+engineered ties in tests/test_serve.py).
 
 Dispatch goes through :class:`repro.kernels.policy.KernelPolicy`
 (``policy.serve_impl``): the Pallas train impls select the Pallas tile
-kernel, everything else the XLA path, and ``"auto"`` follows the train
-rule (Pallas on TPU).  Like the train kernels, the Pallas path runs
+kernel, everything else the XLA path, and ``"auto"`` picks the Pallas
+kernel on TPU.  Like the train kernels, the Pallas path runs
 ``interpret=True`` off-TPU.
 
 Rank padding note: the Pallas path pads ``k_rank`` to the 128-lane VPU
@@ -54,28 +63,32 @@ from ..kernels.policy import KernelPolicy
 
 LANE = 128
 
+#: the scorers' matmul precision.  f32 scores are computed at full f32
+#: precision on every backend: TPU's default f32 matmul rounds its inputs
+#: to bf16, whose score error (~1e-3 relative) would reorder near-tied
+#: items against any f32 or f64 reference.
+_PRECISION = jax.lax.Precision.HIGHEST
+
 __all__ = ["topk_scores", "topk_scores_filtered", "topk_dense_oracle"]
 
 
 def topk_dense_oracle(W_u, H, k_top: int, h_scale=None):
-    """Dense reference: materialize ``W_u @ H.T`` and stably argsort.
+    """Dense reference: float64 ``W_u @ H.T`` on the host, stably
+    argsorted.
 
-    Scores use the same jnp matmul as the tiled paths (selection must be
-    the only thing that differs); the ordering is an independent host
+    The scores are computed in float64 from the given factors, so they
+    are independent of any device matmul; the ordering is
     ``np.argsort(-scores, kind="stable")``, i.e. score-descending with
     ties broken by smaller item id.  With ``h_scale`` (int8-quantized
     serving) the per-item dequantization scale multiplies the raw score
-    *after* the dot — the same scale-after-sum order the tiled scorers
-    use, which is what makes oracle-vs-tiled exact rather than merely
-    close.  Returns ``(scores, ids)`` of shape ``(U, k_top)``.
+    after the dot, as in the tiled scorers.  Returns ``(scores, ids)``
+    of shape ``(U, k_top)``, scores in float64.
     """
-    Hm = jnp.asarray(H)
-    W_u = jnp.asarray(W_u)
+    W_u = np.asarray(jnp.asarray(W_u).astype(jnp.float32), np.float64)
+    Hm = np.asarray(jnp.asarray(H).astype(jnp.float32), np.float64)
+    scores = W_u @ Hm.T
     if h_scale is not None:
-        scores = np.asarray((W_u @ Hm.astype(W_u.dtype).T)
-                            * jnp.asarray(h_scale)[None, :])
-    else:
-        scores = np.asarray(W_u @ Hm.T)
+        scores = scores * np.asarray(h_scale, np.float64)[None, :]
     order = np.argsort(-scores, axis=1, kind="stable")[:, :k_top]
     return np.take_along_axis(scores, order, axis=1), \
         order.astype(np.int32)
@@ -97,7 +110,8 @@ def topk_scores(W_u, H, k_top: int, *,
                  scores become ``(W_u @ Hq.T) * h_scale``
 
     Returns ``(scores, ids)`` — both ``(U, k_top)``, score-descending,
-    ties by smaller id; exact vs. :func:`topk_dense_oracle`.
+    ties by smaller id; matches :func:`topk_dense_oracle` up to the f32
+    score bound (module docstring).
     """
     policy = KernelPolicy.coerce(policy)
     n = int(H.shape[0])
@@ -185,14 +199,18 @@ def _topk_xla(W_u, H, h_scale, *, k_top: int, item_tile: int):
         run_s, run_i = carry
         if hs_tiles is not None:
             tile, base, hs = xs
-            scores = (W_u @ tile.astype(W_u.dtype).T) * hs[None, :]
+            scores = jnp.dot(W_u, tile.astype(W_u.dtype).T,
+                             precision=_PRECISION) * hs[None, :]
         else:
             tile, base = xs
-            scores = W_u @ tile.T                       # (U, T)
+            scores = jnp.dot(W_u, tile.T, precision=_PRECISION)  # (U, T)
         ids = base + jnp.arange(T, dtype=jnp.int32)
         # catalog padding (and any genuine -inf score) parks on the
-        # sentinel id n, which sorts after every real item
+        # sentinel id n, which sorts after every real item; -0.0 becomes
+        # +0.0, since top_k orders -0.0 below +0.0 and would break the
+        # smaller-id rule for that tie
         scores = jnp.where((ids < n)[None, :], scores, -jnp.inf)
+        scores = jnp.where(scores == 0, 0.0, scores)
         cand_s, li = jax.lax.top_k(scores, kk)
         cand_i = jnp.where(jnp.isneginf(cand_s), n, base + li)
         # merge: running ids all precede this tile's ids, and both parts
@@ -256,10 +274,11 @@ def _topk_kernel(scalars_ref, Wu_ref, Ht_ref, *rest, k_top: int,
         # int8 item tile: dequantize the *score* (one multiply per
         # element, after the dot) instead of the tile (T x k multiplies)
         scores = jnp.dot(Wu_ref[...], Ht_ref[...].astype(Wu_ref.dtype).T,
+                         precision=_PRECISION,
                          preferred_element_type=s_ref.dtype)
         scores = scores * hs_ref[...][None, :]
     else:
-        scores = jnp.dot(Wu_ref[...], Ht_ref[...].T,
+        scores = jnp.dot(Wu_ref[...], Ht_ref[...].T, precision=_PRECISION,
                          preferred_element_type=s_ref.dtype)     # (U, T)
     ids = step * tile + jax.lax.broadcasted_iota(jnp.int32, (U, tile), 1)
     scores = jnp.where(ids < n, scores, -jnp.inf)

@@ -31,7 +31,7 @@ def run_sub(body: str, n_dev: int = 8, timeout: int = 480):
 def test_ring_matmuls_match_references():
     run_sub("""
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        shard_map = jax.shard_map
         from repro.distributed import ring
         from repro.launch.mesh import make_mc_mesh
         mesh = make_mc_mesh(8)
@@ -299,8 +299,7 @@ def test_dryrun_production_meshes_tiny_arch():
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes > 0
-        from repro import compat
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         assert cost.get("flops", 0) > 0
         print("multi-pod dryrun ok:", int(mem.temp_size_in_bytes / 1e6),
               "MB temp")

@@ -232,3 +232,61 @@ def test_grid_kernel_compiled_on_accelerator(requires_gpu):
     )(Ws, Hs, wrs, wcs, wvs, wms)
     np.testing.assert_allclose(Wg, Wr, rtol=2e-5, atol=2e-6)
     np.testing.assert_allclose(Hg, Hr, rtol=2e-5, atol=2e-6)
+
+
+# --------------------------------------------------------------------- #
+# compiled-kernel refusals and dispatch rules                            #
+# --------------------------------------------------------------------- #
+
+def _shapes(lead, m_t, n_t, k, ratings, dtype=jnp.float32):
+    S = jax.ShapeDtypeStruct
+    return (S(lead + (m_t, k), dtype), S(lead + (n_t, k), dtype),
+            S(lead + ratings, jnp.int32), S(lead + ratings, jnp.int32),
+            S(lead + ratings, dtype), S(lead + ratings, jnp.bool_))
+
+
+_KERNELS = {
+    "block": ((), (4096,)),
+    "waves_block": ((), (64, 8)),
+    "waves_grid": ((4,), (64, 8)),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_KERNELS))
+def test_compiled_kernel_refuses_netflix_cell(kernel):
+    """A compiled Pallas SGD kernel asked to hold one worker's Netflix
+    shard (331k rows at p=8) raises before lowering, naming VMEM."""
+    from repro.kernels import nomad_sgd
+    lead, ratings = _KERNELS[kernel]
+    fn = getattr(nomad_sgd, "nomad_sgd_" + kernel)
+    args = _shapes(lead, 331_179, 2_222, 100, ratings)
+    with pytest.raises(ValueError, match="VMEM"):
+        jax.eval_shape(lambda *a: fn(*a, 0.01, 0.05, interpret=False),
+                       *args)
+
+
+@pytest.mark.parametrize("kernel", sorted(_KERNELS))
+def test_compiled_kernel_refuses_packed_dtype(kernel):
+    from repro.kernels import nomad_sgd
+    lead, ratings = _KERNELS[kernel]
+    fn = getattr(nomad_sgd, "nomad_sgd_" + kernel)
+    args = _shapes(lead, 64, 32, 100, ratings, jnp.bfloat16)
+    with pytest.raises(ValueError, match="float32"):
+        jax.eval_shape(lambda *a: fn(*a, 0.01, 0.05, interpret=False,
+                                     accum_fp32=True), *args)
+
+
+@pytest.mark.parametrize("tpu", [False, True])
+def test_dispatch_rules_pick_only_what_compiles(monkeypatch, tpu):
+    """``auto`` trains with the XLA update on every backend, and the
+    grid kernel is chosen only for cells whose tiles fit VMEM."""
+    from repro.kernels import ops
+    from repro.kernels.policy import KernelPolicy
+    monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+    assert ops._resolve(KernelPolicy(impl="auto"), "auto", 1024, 8)[1] \
+        == "xla"
+    pol = KernelPolicy(impl="wave_pallas")
+    assert pol.wants_grid(4096, 1024, 100) == tpu
+    assert not pol.wants_grid(331_179, 2_222, 100)
+    assert KernelPolicy(impl="auto").serve_impl == \
+        ("pallas" if tpu else "xla")

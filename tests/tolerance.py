@@ -20,6 +20,12 @@ principled ones, derived from the storage format, not hand-tuned
   the same held-out RMSE as the fp32 run within a relative band, and
   must actually have converged (final < initial).  Precision changes the
   arithmetic, not the optimization problem.
+* :func:`assert_topk_within_bound` — serving top-k against the float64
+  dense scores: every score within the f32 dot product's forward error
+  bound (:func:`dot_error_bound`), and a selection that no item left
+  out, nor any reordering of the served ones, beats by more than that
+  bound.  Device matmuls sum in shape- and backend-dependent order, so
+  this is the contract the scorers can keep everywhere.
 * :func:`assert_bitwise` — the existing currency, importable from the
   same place so a test file can state both regimes side by side.
 
@@ -30,7 +36,8 @@ from __future__ import annotations
 import numpy as np
 
 __all__ = ["EPS", "rmse", "rel_err_in_eps", "assert_bitwise",
-           "assert_factors_close", "assert_convergence_equivalent"]
+           "assert_factors_close", "assert_convergence_equivalent",
+           "dot_error_bound", "assert_topk_within_bound"]
 
 # machine epsilon (unit roundoff) per storage policy
 EPS = {
@@ -99,3 +106,60 @@ def assert_convergence_equivalent(trace_lowp, trace_fp32, *,
         f"{what}: final gap {gap:.4g} exceeds {rel:.0%} of fp32 final "
         f"{fp[-1]:.4g}")
     return gap
+
+
+def dot_error_bound(A, B, scale=None):
+    """Elementwise bound on ``|fl32(A @ B.T) - (A @ B.T)|`` for any
+    summation order: ``gamma_{k+1} * sum_i |a_i b_i|`` (times ``|scale|``
+    per column when a per-item scale multiplies the dot afterwards), with
+    ``gamma_j = j u / (1 - j u)`` and ``u = eps(fp32)`` — k product
+    roundings and additions, plus one for the scale."""
+    A, B = np.abs(_f64(A)), np.abs(_f64(B))
+    j = A.shape[-1] + 1
+    u = EPS["fp32"]
+    bound = (j * u / (1 - j * u)) * (A @ B.T)
+    if scale is not None:
+        bound = bound * np.abs(_f64(scale))[None, :]
+    return bound
+
+
+def assert_topk_within_bound(ids, scores, W_u, H, *, h_scale=None,
+                             what: str = "top-k"):
+    """A served top-k against the float64 dense scores of ``W_u @ H.T``.
+
+    With ``b`` the :func:`dot_error_bound` and ``x`` the exact scores,
+    per user row: ids are distinct catalog rows; every served score is
+    within ``b`` of ``x``; consecutive served items are in score order
+    up to ``b``; and no item left out beats the weakest served one by
+    more than the two bounds.  So ids equal the dense argsort's except
+    where exact scores lie within the bound of each other.  Returns the
+    largest score error in units of its bound."""
+    ids = np.asarray(ids).astype(np.int64)
+    s = _f64(scores)
+    x = _f64(W_u) @ _f64(H).T
+    if h_scale is not None:
+        x = x * _f64(h_scale)[None, :]
+    b = dot_error_bound(W_u, H, h_scale)
+    n = x.shape[1]
+    worst = 0.0
+    for u in range(ids.shape[0]):
+        got = ids[u]
+        assert len(set(got.tolist())) == len(got) and got.min() >= 0 \
+            and got.max() < n, f"{what}: user {u} ids {got} invalid"
+        xs, bs = x[u, got], b[u, got]
+        err = np.abs(s[u] - xs)
+        assert np.all(err <= bs), (
+            f"{what}: user {u} score error {err.max():.3e} exceeds its "
+            f"bound {bs[np.argmax(err - bs)]:.3e}")
+        worst = max(worst, float(np.max(err / np.maximum(bs, 1e-300))))
+        assert np.all(xs[:-1] + bs[:-1] >= xs[1:] - bs[1:]), (
+            f"{what}: user {u} served order {got} contradicts the exact "
+            "scores beyond the bound")
+        out = np.ones(n, bool)
+        out[got] = False
+        if out.any():
+            best_out = np.max(x[u, out] - b[u, out])
+            assert np.min(xs + bs) >= best_out, (
+                f"{what}: user {u} left out an item scoring "
+                f"{best_out:.6g} (minus bound) above a served one")
+    return worst
