@@ -40,6 +40,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple, Type, Union
 
 import numpy as np
 
+from . import obs
 from .core import partition as part
 from .core.schedule import (OwnershipSchedule, SCHEDULE_NAMES,
                             TransitionSchedule, compile_transition)
@@ -1189,13 +1190,14 @@ def _nomad_cold_start(problem: MCProblem, config: NomadConfig, mesh,
                         waves=policy.wave, sub_blocks=policy.sub_blocks,
                         schedule=config.schedule,
                         schedule_seed=config.schedule_seed)
-    eng = _nomad_engine(br, config, mesh)
-    W0, H0, start = _warm_factors(warm_start, dtype=problem.dtype)
-    if W0 is None:
-        W0, H0 = init_factors(jax.random.key(config.seed), problem.m,
-                              problem.n, config.k)
-        W0, H0 = np.asarray(W0), np.asarray(H0)
-    eng.init_factors(W0, H0)
+    with obs.span("repro.cold_start"):
+        eng = _nomad_engine(br, config, mesh)
+        W0, H0, start = _warm_factors(warm_start, dtype=problem.dtype)
+        if W0 is None:
+            W0, H0 = init_factors(jax.random.key(config.seed), problem.m,
+                                  problem.n, config.k)
+            W0, H0 = np.asarray(W0), np.asarray(H0)
+        eng.init_factors(W0, H0)
     return eng, start
 
 

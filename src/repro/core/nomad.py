@@ -76,6 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import obs
 from . import partition as part
 from .schedule import OwnershipSchedule
 from .stepsize import PowerSchedule
@@ -186,13 +187,22 @@ def _stream_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
             functools.partial(kref.sgd_pair, compute_dtype=cd),
             in_axes=(0, 0, 0, None, None))
 
+    # the named scopes reach the compiled program only as op_name
+    # metadata, so a profiler trace can split the slot's device time
+    # into index slicing, row gathers, the update and the two scatters
     def slot(t, carry):
         Wf, Hf = carry
-        r, c, v, m = (jax.lax.dynamic_slice_in_dim(a, t * p, p)
-                      for a in (rows, cols, vals, mask))
-        w_new, h_new = pair(Wf[r], Hf[c], v, lr, lam)
-        Wf = Wf.at[jnp.where(m, r, P)].set(w_new, mode="drop")
-        Hf = Hf.at[jnp.where(m, c, Q)].set(h_new, mode="drop")
+        with jax.named_scope("slot.index"):
+            r, c, v, m = (jax.lax.dynamic_slice_in_dim(a, t * p, p)
+                          for a in (rows, cols, vals, mask))
+        with jax.named_scope("slot.gather"):
+            w, h = Wf[r], Hf[c]
+        with jax.named_scope("slot.sgd"):
+            w_new, h_new = pair(w, h, v, lr, lam)
+        with jax.named_scope("slot.scatter_w"):
+            Wf = Wf.at[jnp.where(m, r, P)].set(w_new, mode="drop")
+        with jax.named_scope("slot.scatter_h"):
+            Hf = Hf.at[jnp.where(m, c, Q)].set(h_new, mode="drop")
         return Wf, Hf
 
     Wf, Hf = jax.lax.fori_loop(0, rows.shape[0] // p, slot, (Wf, Hf))
@@ -440,6 +450,7 @@ class NomadRingEngine:
         self._entry = None if ent is None else jnp.asarray(ent)
         self._eval_cache = None
         self._stream = None     # fused-driver stream, built on first use
+        self._stream_counts = None
         # local executor: cell arrays are loaded lazily by _cell_data()
         # (the default fused dispatch for the pure-XLA impls only reads
         # the epoch stream — don't keep a second, padded device copy of
@@ -680,16 +691,27 @@ class NomadRingEngine:
         if dispatch not in ("loop", "fused"):
             raise ValueError(
                 f"dispatch={dispatch!r} not in ('loop', 'fused')")
-        if dispatch == "fused":
-            return self._train_fused(epochs, test, verbose, record_every,
-                                     fuse_epochs)
+        obs.count("train.calls")
+        obs.count("train.epochs", epochs)
+        with obs.span("repro.train", epochs=epochs):
+            if dispatch == "fused":
+                return self._train_fused(epochs, test, verbose,
+                                         record_every, fuse_epochs)
+            return self._train_loop(epochs, test, verbose, record_every)
+
+    def _train_loop(self, epochs: int, test, verbose, record_every: int):
+        """Loop dispatch: one device program and one host sync per
+        epoch."""
         recs = set(_record_slots(epochs, record_every, test is not None))
-        eval_args = self._eval_args(test) if recs else None
+        with obs.span("repro.train.stage"):
+            eval_args = self._eval_args(test) if recs else None
         trace = []
         for i in range(1, epochs + 1):
-            self.run_epoch()
+            with obs.span("repro.train.dispatch"):
+                self.run_epoch()
             if i in recs:
-                r = float(_sharded_rmse(self.Ws, self.Hs, *eval_args))
+                with obs.span("repro.train.sync"):
+                    r = float(_sharded_rmse(self.Ws, self.Hs, *eval_args))
                 trace.append((self.epoch_idx, r))
                 if verbose:
                     print(f"epoch {self.epoch_idx}: test rmse {r:.4f}")
@@ -697,9 +719,32 @@ class NomadRingEngine:
         # SGD updates, so one end-of-call check is exact (and the only
         # extra sync the loop path pays)
         if epochs > 0:
-            self.last_finite = bool(jnp.isfinite(self.Ws).all()
-                                    & jnp.isfinite(self.Hs).all())
+            with obs.span("repro.train.sync"):
+                self.last_finite = bool(jnp.isfinite(self.Ws).all()
+                                        & jnp.isfinite(self.Hs).all())
         return trace
+
+    @property
+    def stream_counts(self):
+        """``(slots, updates)`` of the fused driver's epoch stream: its
+        length in ``p``-wide slots and its real (unmasked) entries, the
+        updates one epoch applies.  None until a fused call builds it."""
+        return self._stream_counts
+
+    def _build_stream(self):
+        """The flat epoch stream (``partition.epoch_stream``) on the
+        device.  With tracing on, the upload span waits for the copy to
+        land, so it holds the transfer and not only its enqueue."""
+        with obs.span("repro.stream.build"):
+            R, C, V, M = part.epoch_stream(self.br)
+        with obs.span("repro.stream.upload"):
+            self._stream = tuple(jnp.asarray(a.reshape(-1))
+                                 for a in (R, C, V, M))
+            if obs.enabled():
+                jax.block_until_ready(self._stream)
+        self._stream_counts = (int(R.shape[0]), int(np.count_nonzero(M)))
+        obs.count("stream.slots", self._stream_counts[0])
+        obs.count("stream.updates", self._stream_counts[1])
 
     def _train_fused(self, epochs: int, test, verbose,
                      record_every: int, fuse_epochs: Optional[int]):
@@ -717,11 +762,12 @@ class NomadRingEngine:
         block = fuse_epochs or (1 if verbose else max(epochs, 1))
         start = self.epoch_idx
         recs = _record_slots(epochs, record_every, test is not None)
-        if recs:
-            ridx, cidx, tvals = self._eval_args(test)
-        else:
-            ridx = cidx = jnp.zeros(0, jnp.int32)
-            tvals = jnp.zeros(0, jnp.float32)
+        with obs.span("repro.train.stage"):
+            if recs:
+                ridx, cidx, tvals = self._eval_args(test)
+            else:
+                ridx = cidx = jnp.zeros(0, jnp.int32)
+                tvals = jnp.zeros(0, jnp.float32)
         trace = []
         done = 0
         # duck-typed __call__-only schedules (anything that worked on
@@ -733,40 +779,43 @@ class NomadRingEngine:
                               for i in range(count)], dtype=np.float64))
         while done < epochs:
             c = min(block, epochs - done)
-            lrs = jnp.asarray(values(self.epoch_idx, c),
-                              dtype=self.policy.compute_dtype
-                              or self.Ws.dtype)
-            chunk_recs = [i for i in recs if done < i <= done + c]
-            pos = np.full(c, -1, dtype=np.int32)
-            for j, i in enumerate(chunk_recs):
-                pos[i - done - 1] = j
-            rec_pos = jnp.asarray(pos)
-            if self.mesh is None:
-                if self.policy.impl in _STREAM_IMPLS:
-                    if self._stream is None:
-                        self._stream = tuple(
-                            jnp.asarray(a.reshape(-1))
-                            for a in part.epoch_stream(self.br))
+            with obs.span("repro.train.stage"):
+                lrs = jnp.asarray(values(self.epoch_idx, c),
+                                  dtype=self.policy.compute_dtype
+                                  or self.Ws.dtype)
+                chunk_recs = [i for i in recs if done < i <= done + c]
+                pos = np.full(c, -1, dtype=np.int32)
+                for j, i in enumerate(chunk_recs):
+                    pos[i - done - 1] = j
+                rec_pos = jnp.asarray(pos)
+            stream = (self.mesh is None
+                      and self.policy.impl in _STREAM_IMPLS)
+            if stream and self._stream is None:
+                self._build_stream()
+            # a first call traces and compiles inside the dispatch span
+            with obs.span("repro.train.dispatch"):
+                if stream:
                     self.Ws, self.Hs, tr, ok = _local_train_stream(
                         self.Ws, self.Hs, self._stream, lrs, rec_pos,
                         self.lam, ridx, cidx, tvals, policy=self.policy,
                         entry=self._entry, n_rec=len(chunk_recs))
-                else:
+                elif self.mesh is None:
                     data = (*self._cell_data(), self._perm_src)
                     self.Ws, self.Hs, tr, ok = _local_train_steps(
                         self.Ws, self.Hs, data, lrs, rec_pos, self.lam,
                         ridx, cidx, tvals, policy=self.policy,
                         entry=self._entry, n_rec=len(chunk_recs))
-            else:
-                data = (self.rows, self.cols, self.vals, self.mask)
-                self.Ws, self.Hs, tr, ok = self._spmd_train(
-                    self.Ws, self.Hs, data, lrs, rec_pos, self.lam,
-                    ridx, cidx, tvals, policy=self.policy,
-                    n_rec=len(chunk_recs))
+                else:
+                    data = (self.rows, self.cols, self.vals, self.mask)
+                    self.Ws, self.Hs, tr, ok = self._spmd_train(
+                        self.Ws, self.Hs, data, lrs, rec_pos, self.lam,
+                        ridx, cidx, tvals, policy=self.policy,
+                        n_rec=len(chunk_recs))
             self.epoch_idx += c
             done += c
-            tr = np.asarray(tr)        # the block's single host sync
-            self.last_finite = bool(ok)   # rides the same sync
+            with obs.span("repro.train.sync"):
+                tr = np.asarray(tr)        # the block's single host sync
+                self.last_finite = bool(ok)   # rides the same sync
             for j, i in enumerate(chunk_recs):
                 trace.append((start + i, float(tr[j])))
                 if verbose:

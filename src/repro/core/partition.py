@@ -39,6 +39,7 @@ from typing import Optional, Tuple, Union
 
 import numpy as np
 
+from .. import obs
 from .schedule import (OwnershipSchedule, TransitionSchedule, greedy_fill,
                        greedy_two_resource_color)
 
@@ -518,67 +519,75 @@ def pack(
     from a simulator run by ``OwnershipSchedule.from_sim_log``).
     ``schedule_seed`` seeds the random/balanced constructors.
     """
-    rows = np.asarray(rows, dtype=np.int64)
-    cols = np.asarray(cols, dtype=np.int64)
-    vals_f = np.asarray(vals, dtype=np.float32)
+    with obs.span("repro.pack", p=p):
+        obs.count("pack.ratings", len(rows))
+        with obs.span("repro.pack.assign"):
+            rows = np.asarray(rows, dtype=np.int64)
+            cols = np.asarray(cols, dtype=np.int64)
+            vals_f = np.asarray(vals, dtype=np.float32)
+            row_cnt = np.bincount(rows, minlength=m)
+            col_cnt = np.bincount(cols, minlength=n)
+            if row_owner is not None:
+                row_owner = _validate_assign(row_owner, m, p, "row_owner")
+            elif balanced:
+                row_owner = balanced_assign(row_cnt, p)
+            else:
+                row_owner = contiguous_assign(m, p)
+            if col_block is not None:
+                col_block = _validate_assign(col_block, n, p, "col_block")
+            elif balanced:
+                col_block = balanced_assign(col_cnt, p)
+            else:
+                col_block = contiguous_assign(n, p)
+            (m_local, n_local, row_local, col_local, row_of,
+             col_of) = _localize(row_owner, col_block, m, n, p)
 
-    row_cnt = np.bincount(rows, minlength=m)
-    col_cnt = np.bincount(cols, minlength=n)
-    if row_owner is not None:
-        row_owner = _validate_assign(row_owner, m, p, "row_owner")
-    elif balanced:
-        row_owner = balanced_assign(row_cnt, p)
-    else:
-        row_owner = contiguous_assign(m, p)
-    if col_block is not None:
-        col_block = _validate_assign(col_block, n, p, "col_block")
-    elif balanced:
-        col_block = balanced_assign(col_cnt, p)
-    else:
-        col_block = contiguous_assign(n, p)
+        if sub_blocks < 1:
+            raise ValueError("sub_blocks must be >= 1")
+        if sub_blocks > 1 and n_local // sub_blocks == 0:
+            raise ValueError(f"sub_blocks={sub_blocks} > n_local={n_local}")
+        sub_starts = sub_block_starts(n_local, sub_blocks)
+        sb = max(1, n_local // sub_blocks)
 
-    m_local, n_local, row_local, col_local, row_of, col_of = _localize(
-        row_owner, col_block, m, n, p)
+        with obs.span("repro.pack.sort"):
+            # assign each rating to its cell; sort within cell by (col, row)
+            cell_q = row_owner[rows]
+            cell_b = col_block[cols]
+            cell_id = cell_q.astype(np.int64) * p + cell_b
+            order = np.lexsort((rows, cols, cell_id))
+            counts = np.bincount(cell_id[order],
+                                 minlength=p * p).reshape(p, p)
+            # resolve the schedule spec now that per-cell loads are known
+            # (the balanced constructor spreads by nnz_cell)
+            sched = OwnershipSchedule.resolve(schedule, p,
+                                              seed=schedule_seed,
+                                              loads=counts)
 
-    if sub_blocks < 1:
-        raise ValueError("sub_blocks must be >= 1")
-    if sub_blocks > 1 and n_local // sub_blocks == 0:
-        raise ValueError(f"sub_blocks={sub_blocks} > n_local={n_local}")
-    sub_starts = sub_block_starts(n_local, sub_blocks)
-    sb = max(1, n_local // sub_blocks)
+        # ---- pass 1: per cell, order ratings (sub-block-major, wave-major)
+        # cell_info[q][s] = (ids, rloc, cloc, wave, sid) in final serial
+        # order
+        with obs.span("repro.pack.order"):
+            starts = np.concatenate([[0], np.cumsum(counts.reshape(-1))])
+            cell_info = [[_empty_cell(waves)] * sched.n_steps
+                         for _ in range(p)]
+            for q in range(p):
+                for b in range(p):
+                    lo, hi = starts[q * p + b], starts[q * p + b + 1]
+                    ids = order[lo:hi]
+                    s = int(sched.step_of[q, b])  # step at which q executes b
+                    cell_info[q][s] = _order_cell(
+                        ids, row_local[rows[ids]], col_local[cols[ids]],
+                        waves=waves, sub_blocks=sub_blocks, sb=sb)
 
-    # assign each rating to its cell; sort within cell by (col, row)
-    cell_q = row_owner[rows]
-    cell_b = col_block[cols]
-    cell_id = cell_q.astype(np.int64) * p + cell_b
-    order = np.lexsort((rows, cols, cell_id))
-    counts = np.bincount(cell_id[order], minlength=p * p).reshape(p, p)
-
-    # resolve the schedule spec now that per-cell loads are known (the
-    # balanced constructor spreads by nnz_cell)
-    sched = OwnershipSchedule.resolve(schedule, p, seed=schedule_seed,
-                                      loads=counts)
-
-    # ---- pass 1: per cell, order ratings (sub-block-major, wave-major) --
-    # cell_info[q][s] = (ids, rloc, cloc, wave, sid) in final serial order
-    starts = np.concatenate([[0], np.cumsum(counts.reshape(-1))])
-    cell_info = [[_empty_cell(waves)] * sched.n_steps for _ in range(p)]
-    for q in range(p):
-        for b in range(p):
-            lo, hi = starts[q * p + b], starts[q * p + b + 1]
-            ids = order[lo:hi]
-            s = int(sched.step_of[q, b])  # step at which q executes b
-            cell_info[q][s] = _order_cell(
-                ids, row_local[rows[ids]], col_local[cols[ids]],
-                waves=waves, sub_blocks=sub_blocks, sb=sb)
-
-    # ---- pass 2: compute padded dims and fill the layouts --------------
-    return _fill_layouts(
-        cell_info, vals_f, p=p, m=m, n=n, m_local=m_local,
-        n_local=n_local, row_owner=row_owner, row_local=row_local,
-        col_block=col_block, col_local=col_local, row_of=row_of,
-        col_of=col_of, waves=waves, wave_width=wave_width,
-        sub_blocks=sub_blocks, sub_starts=sub_starts, schedule=sched)
+        # ---- pass 2: compute padded dims and fill the layouts ----------
+        with obs.span("repro.pack.fill"):
+            return _fill_layouts(
+                cell_info, vals_f, p=p, m=m, n=n, m_local=m_local,
+                n_local=n_local, row_owner=row_owner, row_local=row_local,
+                col_block=col_block, col_local=col_local, row_of=row_of,
+                col_of=col_of, waves=waves, wave_width=wave_width,
+                sub_blocks=sub_blocks, sub_starts=sub_starts,
+                schedule=sched)
 
 
 def repack_delta(

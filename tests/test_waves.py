@@ -105,6 +105,13 @@ def test_pack_wave_layout_is_conflict_free_partition(seed, p, m, n, nnz,
 @settings(max_examples=15, deadline=None)
 @given(**strategies.PACK_SHAPE)
 def test_pack_wave_layout_property(seed, p, m, n, nnz, sub):
+    # more sub-blocks than a worker's item block has columns is refused
+    rows, cols, vals = strategies.coo_problem(seed, m, n, nnz)
+    n_local = P.pack(rows, cols, vals, m, n, p).n_local
+    if sub > 1 and n_local // sub == 0:
+        with pytest.raises(ValueError, match="sub_blocks"):
+            P.pack(rows, cols, vals, m, n, p, sub_blocks=sub)
+        return
     _check_pack_waves(seed, p, m, n, nnz, sub_blocks=sub)
 
 
