@@ -143,17 +143,19 @@ def _stream_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
                        entry):
     """One epoch over the globalized flat stream
     (``partition.epoch_stream``): a single scan of conflict-free
-    ``p``-wide slots against the flattened home-placement factor arrays
-    — no per-step permutation, no entry gather, no worker vmap.
+    ``p``-wide slots against one factor table, the flattened
+    home-placement W rows followed by the H rows — no per-step
+    permutation, no entry gather, no worker vmap.
 
     Each slot batches up to ``p`` concurrent updates whose rows and
     columns are pairwise disjoint (the generalized-diagonal invariant),
-    so the batched gather -> update -> drop-mode scatter is exactly a
-    sequential execution of the slot; slots run in the packed serial
-    order.  Bitwise equality with the loop path holds per kernel
-    because the slot update reproduces the loop path's own batching:
-    the wave impls' slot is a width-``p`` ``sgd_pair_batch`` (the op
-    ``block_sgd_waves`` applies per wave), the sequential impls' a
+    so the slot's one ``2p``-row gather -> update -> one drop-mode
+    scatter is exactly a sequential execution of the slot; slots run
+    in the packed serial order.  Bitwise equality with the loop path
+    holds per kernel because the slot update reproduces the loop
+    path's own batching: the wave impls' slot is a width-``p``
+    ``sgd_pair_batch`` (the op ``block_sgd_waves`` applies per wave),
+    the sequential impls' a
     worker-vmapped ``sgd_pair`` (the op the worker-vmapped
     ``block_sgd_ref`` scan applies per rating — ``dot`` and
     ``sum(w * h)`` reductions are not interchangeable bit for bit).
@@ -174,12 +176,14 @@ def _stream_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
     rows, cols, vals, mask = data
     p, m_local, k = Ws.shape
     n_local = Hs.shape[1]
-    Wf = Ws.reshape(p * m_local, k)
-    Hf = Hs.reshape(p * n_local, k)
+    P, Q = p * m_local, p * n_local
+    # one factor table, H's rows at offset P: with W and H apart, TPU
+    # keeps the (small) H in VMEM and its scatter stages a copy of all
+    # of H on every slot; one table lives in HBM and is written in place
+    T = jnp.concatenate([Ws.reshape(P, k), Hs.reshape(Q, k)])
     cd = policy.compute_dtype            # None on the fp32 bitwise path
-    lr = jnp.asarray(lr, dtype=cd or Wf.dtype)
-    lam = jnp.asarray(lam, dtype=cd or Wf.dtype)
-    P, Q = Wf.shape[0], Hf.shape[0]
+    lr = jnp.asarray(lr, dtype=cd or T.dtype)
+    lam = jnp.asarray(lam, dtype=cd or T.dtype)
     if policy.wave:
         pair = functools.partial(kref.sgd_pair_batch, compute_dtype=cd)
     else:
@@ -189,24 +193,27 @@ def _stream_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
 
     # the named scopes reach the compiled program only as op_name
     # metadata, so a profiler trace can split the slot's device time
-    # into index slicing, row gathers, the update and the two scatters
-    def slot(t, carry):
-        Wf, Hf = carry
+    # into index slicing, the row gather, the update and the scatter
+    def slot(t, T):
         with jax.named_scope("slot.index"):
             r, c, v, m = (jax.lax.dynamic_slice_in_dim(a, t * p, p)
                           for a in (rows, cols, vals, mask))
+            idx = jnp.concatenate([r, c + P])
         with jax.named_scope("slot.gather"):
-            w, h = Wf[r], Hf[c]
+            g = T[idx]
         with jax.named_scope("slot.sgd"):
-            w_new, h_new = pair(w, h, v, lr, lam)
-        with jax.named_scope("slot.scatter_w"):
-            Wf = Wf.at[jnp.where(m, r, P)].set(w_new, mode="drop")
-        with jax.named_scope("slot.scatter_h"):
-            Hf = Hf.at[jnp.where(m, c, Q)].set(h_new, mode="drop")
-        return Wf, Hf
+            w_new, h_new = pair(g[:p], g[p:], v, lr, lam)
+        # W and H rows lie in disjoint ranges of T, and a slot's live
+        # rows and columns are pairwise distinct, so the 2p live indices
+        # are unique and this one scatter is exactly the two it replaces
+        with jax.named_scope("slot.scatter"):
+            live = jnp.concatenate([m, m])
+            T = T.at[jnp.where(live, idx, P + Q)].set(
+                jnp.concatenate([w_new, h_new]), mode="drop")
+        return T
 
-    Wf, Hf = jax.lax.fori_loop(0, rows.shape[0] // p, slot, (Wf, Hf))
-    return Wf.reshape(p, m_local, k), Hf.reshape(p, n_local, k)
+    T = jax.lax.fori_loop(0, rows.shape[0] // p, slot, T)
+    return T[:P].reshape(p, m_local, k), T[P:].reshape(p, n_local, k)
 
 
 def _steps_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,

@@ -158,6 +158,69 @@ def test_epoch_stream_slots_are_conflict_free_and_complete():
     assert M.sum() == br.mask.sum()
 
 
+def _two_scatter_epoch(Ws, Hs, data, lr, lam, policy):
+    """The stream epoch with W and H kept apart: per slot a gather and a
+    drop-mode scatter of each (the slot body before the one table)."""
+    from repro.kernels import ref as kref
+    rows, cols, vals, mask = data
+    p, _, k = Ws.shape
+    Wf, Hf = Ws.reshape(-1, k), Hs.reshape(-1, k)
+    P, Q = Wf.shape[0], Hf.shape[0]
+    cd = policy.compute_dtype
+    lr = jnp.asarray(lr, dtype=cd or Wf.dtype)
+    lam = jnp.asarray(lam, dtype=cd or Wf.dtype)
+    if policy.wave:
+        pair = lambda *a: kref.sgd_pair_batch(*a, compute_dtype=cd)  # noqa: E731
+    else:
+        pair = jax.vmap(lambda *a: kref.sgd_pair(*a, compute_dtype=cd),
+                        in_axes=(0, 0, 0, None, None))
+
+    def slot(t, carry):
+        Wf, Hf = carry
+        r, c, v, m = (jax.lax.dynamic_slice_in_dim(a, t * p, p)
+                      for a in (rows, cols, vals, mask))
+        w_new, h_new = pair(Wf[r], Hf[c], v, lr, lam)
+        return (Wf.at[jnp.where(m, r, P)].set(w_new, mode="drop"),
+                Hf.at[jnp.where(m, c, Q)].set(h_new, mode="drop"))
+
+    Wf, Hf = jax.lax.fori_loop(0, rows.shape[0] // p, slot, (Wf, Hf))
+    return Wf.reshape(Ws.shape), Hf.reshape(Hs.shape)
+
+
+@pytest.mark.parametrize("dtype_policy", ["fp32", "bf16"])
+@pytest.mark.parametrize("impl", ["xla", "wave"])
+def test_one_table_stream_epoch_equals_two_scatters(impl, dtype_policy):
+    """The stream epoch's one factor table (one gather and one scatter
+    of 2p rows per slot) is bitwise the slot body that gathers and
+    scatters W and H apart, on a stream whose masked lanes read the
+    rows and columns of live lanes in the same slot and carry values."""
+    problem = _problem(seed=12)
+    cfg = _cfg(kernel=impl, dtype_policy=dtype_policy)
+    eng, _ = api._nomad_cold_start(problem, cfg, None, None)
+    R, C, V, M = part.epoch_stream(eng.br)
+    shadowed = 0
+    for t in range(R.shape[0]):
+        live, dead = np.flatnonzero(M[t]), np.flatnonzero(~M[t])
+        if len(live) and len(dead):
+            R[t, dead], C[t, dead] = R[t, live[0]], C[t, live[0]]
+            V[t, dead] = 7.0
+            shadowed += 1
+    assert shadowed >= 3
+    data = tuple(jnp.asarray(a.reshape(-1)) for a in (R, C, V, M))
+    lr = jnp.asarray(0.05, jnp.float32)
+    one = jax.jit(nomad._stream_epoch_body,
+                  static_argnames=("policy", "entry"))(
+        eng.Ws, eng.Hs, data, lr, eng.lam, policy=eng.policy, entry=None)
+    two = jax.jit(_two_scatter_epoch, static_argnames=("policy",))(
+        eng.Ws, eng.Hs, data, lr, eng.lam, policy=eng.policy)
+    for a, b, before in zip(one, two, (eng.Ws, eng.Hs)):
+        assert a.dtype == b.dtype == before.dtype
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+        assert not np.array_equal(np.asarray(a, np.float32),
+                                  np.asarray(before, np.float32))
+
+
 def test_fused_accepts_call_only_stepsize():
     """A duck-typed __call__-only step-size schedule (no .values) that
     worked on the loop path keeps working — and stays bitwise — on the

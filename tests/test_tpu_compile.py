@@ -5,8 +5,9 @@ compiles for a *described* ``v5e:2x2`` topology and refuses what the
 chip's compiler would refuse — a Mosaic kernel it cannot lower, blocks
 beyond VMEM, a program beyond HBM.  These tests compile the main path's
 programs at Netflix widths (k=100, the 17,770-item catalog) and the
-Pallas SGD kernels at the cell shapes they can hold.  Nothing runs, so
-they say nothing about results or times.
+Pallas SGD kernels at the cell shapes they can hold, and read the
+stream epoch's compiled slot loop.  Nothing runs, so they say nothing
+about results or times.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and the test
@@ -15,6 +16,7 @@ around the compiles (a compile for a described chip cannot be read back
 without one).
 """
 import os
+import re
 
 import pytest
 
@@ -61,25 +63,99 @@ def _compile_fits(lowered, *, kernel: bool):
     return compiled
 
 
-def test_stream_driver_compiles_at_netflix_widths(one_chip):
-    """The fused stream driver (``api.solve``'s default path) at p=8,
-    k=100 over one worker's Netflix row shard, with a short stream."""
+def _lower_stream_driver(sharding, impl):
     import jax.numpy as jnp
 
     from repro.core import nomad
     from repro.kernels.policy import KernelPolicy
-    s = lambda shape, dt: _spec(one_chip, shape, dt)    # noqa: E731
+    s = lambda shape, dt: _spec(sharding, shape, dt)    # noqa: E731
     m_local, n_local = -(-NETFLIX_M // P), -(-NETFLIX_N // P)
     slots, n_test = 1 << 16, 4096
     data = (s((slots * P,), jnp.int32), s((slots * P,), jnp.int32),
             s((slots * P,), jnp.float32), s((slots * P,), jnp.bool_))
-    lowered = nomad._local_train_stream.lower(
+    return nomad._local_train_stream.lower(
         s((P, m_local, K), jnp.float32), s((P, n_local, K), jnp.float32),
         data, s((2,), jnp.float32), s((2,), jnp.int32), 0.05,
         s((n_test,), jnp.int32), s((n_test,), jnp.int32),
         s((n_test,), jnp.float32),
-        policy=KernelPolicy(impl="xla"), entry=None, n_rec=2)
-    _compile_fits(lowered, kernel=False)
+        policy=KernelPolicy(impl=impl), entry=None, n_rec=2)
+
+
+@pytest.fixture(scope="module")
+def stream_driver_text(one_chip):
+    """The fused stream driver's compiled text at Netflix widths, one
+    compile per impl for the whole module."""
+    texts = {}
+
+    def get(impl):
+        if impl not in texts:
+            compiled = _compile_fits(_lower_stream_driver(one_chip, impl),
+                                     kernel=False)
+            texts[impl] = compiled.as_text()
+        return texts[impl]
+    return get
+
+
+def test_stream_driver_compiles_at_netflix_widths(stream_driver_text):
+    """The fused stream driver (``api.solve``'s default path) at p=8,
+    k=100 over one worker's Netflix row shard, with a short stream."""
+    assert stream_driver_text("xla")
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$")
+_WHILE_BODY = re.compile(r" while\(.*, body=(%[\w.\-]+)")
+_CALLS = re.compile(r"calls=(%[\w.\-]+)")
+_SCOPED = re.compile(r'"used_scoped_memory_configs":\[([^\]]*)\]')
+
+
+def _computations(text):
+    """Optimized HLO text -> {computation name: its instruction lines}."""
+    out, name = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            out[name].append(line)
+    return out
+
+
+def _scatter_fusions(comps, body):
+    """The fusions of ``body`` that hold a scatter, at any depth of
+    nested fusion."""
+    def holds_scatter(line):
+        m = _CALLS.search(line)
+        return m is not None and any(
+            " scatter(" in x or (" fusion(" in x and holds_scatter(x))
+            for x in comps.get(m.group(1), []))
+    return [x for x in comps[body]
+            if " fusion(" in x and holds_scatter(x)]
+
+
+@pytest.mark.parametrize("impl", ["xla", "wave"])
+def test_stream_slot_body_scatters_rows_in_place(stream_driver_text, impl):
+    """The slot loop writes its rows back with one scatter into the
+    factor table, in HBM and in place.  Kept apart, the 7.1 MB H of the
+    Netflix catalog lives in VMEM and its scatter staged a copy of all
+    of H in scoped memory on every slot (10 MB at the benchmark's
+    shapes), the costliest op of the epoch."""
+    text = stream_driver_text(impl)
+    comps = _computations(text)
+    bodies = {m.group(1) for line in text.splitlines()
+              for m in [_WHILE_BODY.search(line)] if m}
+    slot_bodies = [b for b in bodies if _scatter_fusions(comps, b)]
+    assert len(slot_bodies) == 1
+    (body,) = slot_bodies
+    assert len(_scatter_fusions(comps, body)) == 1
+    for line in comps[body]:
+        if " fusion(" not in line:
+            continue
+        m = _SCOPED.search(line)
+        sizes = re.findall(r'"size":"(\d+)"', m.group(1)) if m else []
+        assert max(map(int, sizes), default=0) < 1 << 20, line[:120]
 
 
 @pytest.mark.parametrize("users", [1, 64])
