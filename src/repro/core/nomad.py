@@ -80,6 +80,7 @@ from .. import obs
 from . import partition as part
 from .schedule import OwnershipSchedule
 from .stepsize import PowerSchedule
+from ..kernels import nomad_stream
 from ..kernels import ops as kops
 from ..kernels import ref as kref
 from ..kernels.policy import KernelPolicy
@@ -216,6 +217,25 @@ def _stream_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
     return T[:P].reshape(p, m_local, k), T[P:].reshape(p, n_local, k)
 
 
+def _kernel_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
+                       entry):
+    """:func:`_stream_epoch_body`'s epoch as one Pallas kernel call
+    (``kernels/nomad_stream.py``, op ``nomad_stream_epoch``): the same
+    slots in the same serial order, with H resident in VMEM and W rows
+    moved by DMA.  ``data`` is the stream with its reuse map
+    (``nomad_stream.stream_map``, built by
+    ``NomadRingEngine._build_stream`` for ``nomad_stream.chunk_slots(p)``
+    slots a chunk).  The kernel reduces the dot in its own order, so it
+    agrees with the XLA stream to rounding; float32 storage only
+    (``policy`` and ``entry`` keep the driver signature uniform).  Off
+    the TPU it runs in the TPU interpreter."""
+    p, m_local, k = Ws.shape
+    W, H = nomad_stream.stream_epoch(
+        Ws.reshape(-1, k), Hs.reshape(-1, k), data, lr, lam, p=p,
+        chunk=nomad_stream.chunk_slots(p), interpret=not kops.on_tpu())
+    return W.reshape(Ws.shape), H.reshape(Hs.shape)
+
+
 def _steps_epoch_body(Ws, Hs, data, lr, lam, policy: KernelPolicy,
                       entry):
     """:func:`_local_epoch_body` adapted to the fused driver's
@@ -280,9 +300,25 @@ def _fused_driver(epoch_body):
 #: for the rest — both bitwise-equal to the per-epoch loop path.
 _local_train_stream = _fused_driver(_stream_epoch_body)
 _local_train_steps = _fused_driver(_steps_epoch_body)
+#: the stream's epoch as one Pallas kernel, where :func:`_takes_kernel`
+_local_train_kernel = _fused_driver(_kernel_epoch_body)
 
 #: impls whose fused local driver consumes the flattened epoch stream
 _STREAM_IMPLS = ("xla", "wave")
+
+
+def _takes_kernel(platform: str, mesh, policy: KernelPolicy, dtype,
+                  p: int, n_local: int, k: int) -> bool:
+    """Whether a fused call runs the stream as the Pallas epoch kernel
+    (:func:`_local_train_kernel`) instead of the XLA slot loop
+    (:func:`_local_train_stream`), from what the engine can observe: a
+    TPU, no mesh, a stream impl, float32 storage (Mosaic cannot move a
+    one-row slice of a packed dtype) and an H of ``p * n_local`` rows
+    that fits VMEM beside the kernel's W buffers."""
+    return (platform == "tpu" and mesh is None
+            and policy.impl in _STREAM_IMPLS and not policy.mixed
+            and jnp.dtype(dtype) == jnp.float32
+            and nomad_stream.fits_vmem(p * n_local, k, p))
 
 
 def _spmd_epoch_fn(p: int, axis: str, lam: float, policy: KernelPolicy,
@@ -738,20 +774,39 @@ class NomadRingEngine:
         updates one epoch applies.  None until a fused call builds it."""
         return self._stream_counts
 
-    def _build_stream(self):
+    def _takes_kernel(self) -> bool:
+        """:func:`_takes_kernel` for this engine's device, mesh, policy
+        and shapes."""
+        platform = next(iter(self.Ws.devices())).platform
+        p, n_local, k = self.Hs.shape
+        return _takes_kernel(platform, self.mesh, self.policy,
+                             self.Ws.dtype, p, n_local, k)
+
+    def _build_stream(self, kernel: bool = False):
         """The flat epoch stream (``partition.epoch_stream``) on the
-        device.  With tracing on, the upload span waits for the copy to
-        land, so it holds the transfer and not only its enqueue."""
+        device; for the epoch kernel, the stream with its reuse map
+        (``nomad_stream.stream_map``) instead.  With tracing on, the
+        upload span waits for the copy to land, so it holds the transfer
+        and not only its enqueue."""
         with obs.span("repro.stream.build"):
             R, C, V, M = part.epoch_stream(self.br)
+            if kernel:
+                sm = nomad_stream.stream_map(
+                    R, C, V, M, self.br.p * self.br.n_local,
+                    nomad_stream.chunk_slots(self.br.p))
+                arrays = sm[:4]
+            else:
+                arrays = tuple(a.reshape(-1) for a in (R, C, V, M))
         with obs.span("repro.stream.upload"):
-            self._stream = tuple(jnp.asarray(a.reshape(-1))
-                                 for a in (R, C, V, M))
+            self._stream = tuple(jnp.asarray(a) for a in arrays)
             if obs.enabled():
                 jax.block_until_ready(self._stream)
         self._stream_counts = (int(R.shape[0]), int(np.count_nonzero(M)))
         obs.count("stream.slots", self._stream_counts[0])
         obs.count("stream.updates", self._stream_counts[1])
+        if kernel:
+            obs.count("stream.w_loads", sm.loads)
+            obs.count("stream.w_reuses", sm.reuses)
 
     def _train_fused(self, epochs: int, test, verbose,
                      record_every: int, fuse_epochs: Optional[int]):
@@ -797,12 +852,17 @@ class NomadRingEngine:
                 rec_pos = jnp.asarray(pos)
             stream = (self.mesh is None
                       and self.policy.impl in _STREAM_IMPLS)
+            kernel = stream and self._takes_kernel()
             if stream and self._stream is None:
-                self._build_stream()
+                self._build_stream(kernel)
+            if kernel:
+                obs.count("train.kernel_epochs", c)
             # a first call traces and compiles inside the dispatch span
             with obs.span("repro.train.dispatch"):
                 if stream:
-                    self.Ws, self.Hs, tr, ok = _local_train_stream(
+                    train = (_local_train_kernel if kernel
+                             else _local_train_stream)
+                    self.Ws, self.Hs, tr, ok = train(
                         self.Ws, self.Hs, self._stream, lrs, rec_pos,
                         self.lam, ridx, cidx, tvals, policy=self.policy,
                         entry=self._entry, n_rec=len(chunk_recs))

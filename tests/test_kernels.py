@@ -290,3 +290,202 @@ def test_dispatch_rules_pick_only_what_compiles(monkeypatch, tpu):
     assert not pol.wants_grid(331_179, 2_222, 100)
     assert KernelPolicy(impl="auto").serve_impl == \
         ("pallas" if tpu else "xla")
+
+
+# --------------------------------------------------------------------- #
+# the stream epoch kernel (kernels/nomad_stream.py), TPU interpreter     #
+# --------------------------------------------------------------------- #
+
+def _stream_case(seed, p, m_local, n_local, slots, k=100):
+    """A conflict-free epoch stream over home-placed (p, m_local, k) /
+    (p, n_local, k) shards: lane q updates rows of W shard q, a slot's
+    columns are distinct, column 0 is hot (in some lane of 3 slots in
+    4), about 15% of entries are masked, and every 7th slot is fully
+    masked.  Few rows per shard, so W rows repeat within and across
+    chunks."""
+    rng = np.random.default_rng(seed)
+    Q = p * n_local
+    R = (rng.integers(0, m_local, (slots, p))
+         + m_local * np.arange(p)).astype(np.int32)
+    C = np.stack([rng.permutation(Q)[:p] for _ in range(slots)])
+    hot = rng.random(slots) < 0.75
+    lane = rng.integers(0, p, slots)
+    for t in np.flatnonzero(hot):
+        C[t, C[t] == 0] = C[t, lane[t]]
+        C[t, lane[t]] = 0
+    M = rng.random((slots, p)) < 0.85
+    M[::7] = False
+    V = rng.normal(size=(slots, p)).astype(np.float32)
+    Ws = jnp.asarray(0.3 * rng.normal(size=(p, m_local, k)), jnp.float32)
+    Hs = jnp.asarray(0.3 * rng.normal(size=(p, n_local, k)), jnp.float32)
+    return Ws, Hs, (R, C.astype(np.int32), V, M)
+
+
+def _xla_stream_epoch(Ws, Hs, stream, lr, lam, impl):
+    from repro.core import nomad
+    from repro.kernels.policy import KernelPolicy
+    data = tuple(jnp.asarray(a.reshape(-1)) for a in stream)
+    return jax.jit(nomad._stream_epoch_body,
+                   static_argnames=("policy", "entry"))(
+        Ws, Hs, data, jnp.float32(lr), lam,
+        policy=KernelPolicy(impl=impl), entry=None)
+
+
+def _repeat_gaps(stream, B):
+    """Chunk distance between consecutive live updates of each W row."""
+    R, M = stream[0].reshape(-1), stream[3].reshape(-1)
+    e = np.flatnonzero(M)
+    e = e[np.argsort(R[e], kind="stable")]
+    same = R[e][1:] == R[e][:-1]
+    return (e[1:][same] // B - e[:-1][same] // B).tolist()
+
+
+def _kernel_stream_epoch(Ws, Hs, stream, lr, lam, chunk, interpret=True):
+    from repro.kernels import nomad_stream
+    p, _, k = Ws.shape
+    sm = nomad_stream.stream_map(*stream, Hs.shape[0] * Hs.shape[1], chunk)
+    W, H = nomad_stream.stream_epoch(
+        Ws.reshape(-1, k), Hs.reshape(-1, k),
+        tuple(jnp.asarray(a) for a in sm[:4]), jnp.float32(lr), lam,
+        p=p, chunk=chunk, interpret=interpret)
+    return W.reshape(Ws.shape), H.reshape(Hs.shape), sm
+
+
+@pytest.mark.parametrize("impl", ["xla", "wave"])
+@pytest.mark.parametrize("seed,p,slots,chunk", [
+    (0, 4, 45, 4),    # 12 chunks, the last holding 1 slot
+    (1, 8, 30, 2),    # chunks of 16 entries over 6 rows a shard
+    (2, 2, 61, 8),    # ragged: 61 = 7 x 8 + 5
+])
+def test_stream_kernel_matches_xla_stream(impl, seed, p, slots, chunk):
+    """The kernel's epoch equals the XLA stream's within 1e-6 (the dot
+    is reduced over 128 lanes in another order), on streams with a hot
+    column, fully masked slots, a ragged last chunk and W rows that
+    repeat within a chunk and across adjacent ones."""
+    Ws, Hs, stream = _stream_case(seed, p, 6, 5, slots)
+    W_x, H_x = _xla_stream_epoch(Ws, Hs, stream, 0.05, 0.02, impl)
+    W_k, H_k, sm = _kernel_stream_epoch(Ws, Hs, stream, 0.05, 0.02, chunk)
+    gaps = set(_repeat_gaps(stream, chunk * p))
+    assert {0, 1} <= gaps           # repeats within and across chunks
+    assert sm.reuses > 0 and sm.loads > 0
+    for got, want, before in ((W_k, W_x, Ws), (H_k, H_x, Hs)):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+        assert not np.array_equal(np.asarray(got), np.asarray(before))
+
+
+def test_stream_kernel_dma_timing_and_races():
+    """The result does not depend on when the interpreter lands a DMA
+    (at its start or at its wait), and the race detector finds no
+    access racing a DMA."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+    Ws, Hs, stream = _stream_case(3, 4, 6, 5, 24)
+    outs = []
+    for mode in ("eager", "on_wait"):
+        params = pltpu.InterpretParams(dma_execution_mode=mode,
+                                       detect_races=True)
+        outs.append(_kernel_stream_epoch(Ws, Hs, stream, 0.05, 0.02, 2,
+                                         interpret=params)[:2])
+        assert not interpret_pallas_call.races.races_found
+    for a, b in zip(*outs):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def _replay_map(sm, live, p, chunk, window, nbuf):
+    """Replay the kernel's schedule of DMAs and buffer reads on the map,
+    with versions instead of values (a version is the stream index of
+    the update that wrote it; -1 is the epoch's initial row).  Asserts
+    that every live read sees its row's previous update, that no load is
+    issued while a write-back of its row is in flight, and returns the
+    version of each row in HBM at the end."""
+    B = chunk * p
+    n = len(sm.row)
+    n_chunks = max(1, -(-n // B))
+    hbm, latest, buf = {}, {}, {}
+    writes = {}                      # chunk -> [(row, version)]
+    in_flight = set()
+
+    def issue_loads(c):
+        for e in range(c * B, min(n, (c + 1) * B)):
+            if sm.wmap[e] & 1:
+                row = int(sm.row[e])
+                assert row not in in_flight, "load races a write-back"
+                buf[(c % nbuf) * B + e % B] = hbm.get(row, -1)
+
+    def wait_writes(c):
+        for row, version in writes.pop(c, []):
+            hbm[row] = version
+            in_flight.discard(row)
+
+    for c in range(n_chunks):
+        if c == 0:
+            issue_loads(0)
+        if c >= window:
+            wait_writes(c - window)
+        if c + 1 < n_chunks:
+            issue_loads(c + 1)
+        for e in range(c * B, min(n, (c + 1) * B)):
+            own = (c % nbuf) * B + e % B
+            if live[e]:
+                row = int(sm.row[e])
+                assert buf.get(int(sm.wmap[e]) >> 2) == latest.get(row, -1)
+                latest[row] = e
+                buf[own] = e
+            else:
+                buf[own] = None
+        writes[c] = []
+        for e in range(c * B, min(n, (c + 1) * B)):
+            if sm.wmap[e] & 2:
+                row = int(sm.row[e])
+                writes[c].append((row, buf[(c % nbuf) * B + e % B]))
+                in_flight.add(row)
+        if c == n_chunks - 1:
+            for back in range(window - 1, -1, -1):
+                if c - back >= 0:
+                    wait_writes(c - back)
+    assert not writes and not in_flight
+    return hbm, latest
+
+
+@settings(max_examples=40, deadline=None)
+@given(seed=st.integers(0, 10_000), p=st.sampled_from([1, 2, 3, 8]),
+       m_local=st.integers(1, 9), slots=st.integers(1, 80),
+       chunk=st.integers(1, 6))
+def test_stream_map_replay_property(seed, p, m_local, slots, chunk):
+    """Against a naive replay of the kernel's DMA schedule: every read
+    sees its row's latest write, no load races a write-back, and every
+    row's last write of the epoch reaches HBM."""
+    from repro.kernels import nomad_stream
+    _, _, stream = _stream_case(seed, p, m_local, 4, slots, k=1)
+    sm = nomad_stream.stream_map(*stream, p * 4, chunk)
+    live = stream[3].reshape(-1)
+    hbm, latest = _replay_map(sm, live, p, chunk, nomad_stream.WINDOW,
+                              nomad_stream.NBUF)
+    assert hbm == latest
+    assert sm.loads + sm.reuses == live.sum()
+    assert (sm.wmap[~live] & 3 == 0).all()
+    assert (sm.col[~live] == p * 4).all()
+    assert (sm.wmap[live] >> 2 < nomad_stream.NBUF * chunk * p).all()
+
+
+@pytest.mark.parametrize("platform,mesh,impl,dtype_policy,n_items,want", [
+    ("tpu", None, "xla", "fp32", 19_912, True),     # netflix-train's H
+    ("tpu", None, "wave", "fp32", 19_912, True),
+    ("tpu", None, "xla", "fp32", 624_961, False),   # Yahoo! Music's H
+    ("tpu", None, "xla", "bf16", 19_912, False),
+    ("tpu", "mesh", "xla", "fp32", 19_912, False),
+    ("cpu", None, "xla", "fp32", 19_912, False),
+    ("tpu", None, "pallas", "fp32", 19_912, False),
+])
+def test_stream_kernel_path_rule(platform, mesh, impl, dtype_policy,
+                                 n_items, want):
+    """The fused driver takes the epoch kernel on a TPU, without a mesh,
+    for a stream impl over float32 factors whose H fits VMEM; every
+    other case keeps the XLA stream."""
+    from repro.core import nomad
+    from repro.kernels.policy import KernelPolicy
+    policy = KernelPolicy(impl=impl, dtype_policy=dtype_policy)
+    dtype = policy.storage_dtype
+    p, k = 8, 100
+    assert nomad._takes_kernel(platform, mesh, policy, dtype, p,
+                               -(-n_items // p), k) is want

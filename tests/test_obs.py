@@ -182,6 +182,40 @@ def test_traced_fused_train_records_spans_and_counts():
                                           "train.epochs": 2}
 
 
+def test_traced_kernel_path_counts_its_epochs_and_the_reuse_map(
+        monkeypatch):
+    """With the epoch kernel taken (forced here: it is chosen on a TPU
+    only, and runs in the TPU interpreter on the CPU), a fused call
+    counts its kernel epochs, and building the stream counts the W rows
+    the map loads from HBM and the entries that reuse a row in VMEM.
+    The factors agree with the XLA stream's."""
+    from repro.kernels import nomad_stream
+    problem = _problem(seed=4)
+    xla, _ = api._nomad_cold_start(problem, _cfg(), None, None)
+    eng, _ = api._nomad_cold_start(problem, _cfg(), None, None)
+    assert not eng._takes_kernel()          # the CPU takes the XLA stream
+    monkeypatch.setattr(nomad.NomadRingEngine, "_takes_kernel",
+                        lambda self: True)
+    obs.enable()
+    eng.train(1, problem.test, dispatch="fused")
+    counters = obs.snapshot()["counters"]
+    slots, updates = eng.stream_counts
+    R, C, V, M = part.epoch_stream(eng.br)
+    sm = nomad_stream.stream_map(R, C, V, M, eng.br.p * eng.br.n_local,
+                                 nomad_stream.chunk_slots(eng.br.p))
+    assert counters == {"train.calls": 1, "train.epochs": 1,
+                        "train.kernel_epochs": 1, "stream.slots": slots,
+                        "stream.updates": updates,
+                        "stream.w_loads": sm.loads,
+                        "stream.w_reuses": sm.reuses}
+    assert sm.loads + sm.reuses == updates == problem.nnz
+    assert sm.reuses > 0
+    assert len(eng._stream[0]) == slots * eng.br.p
+    xla.train(1, problem.test, dispatch="fused")
+    for a, b in ((eng.Ws, xla.Ws), (eng.Hs, xla.Hs)):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
 def test_traced_loop_train_records_dispatch_and_sync_per_epoch():
     problem = _problem()
     eng, _ = api._nomad_cold_start(problem, _cfg(), None, None)

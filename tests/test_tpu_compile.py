@@ -158,6 +158,39 @@ def test_stream_slot_body_scatters_rows_in_place(stream_driver_text, impl):
         assert max(map(int, sizes), default=0) < 1 << 20, line[:120]
 
 
+def test_stream_kernel_driver_compiles_at_netflix_widths(one_chip,
+                                                        monkeypatch):
+    """The fused driver around the stream epoch kernel, as a v5e runs
+    it: the Netflix catalog's H (19,912 rows, the benchmark packing's
+    ``p * n_local``) resident in VMEM under the kernel's VMEM limit, W
+    in HBM, over a stream with its reuse map."""
+    import jax.numpy as jnp
+
+    from repro.core import nomad
+    from repro.kernels import nomad_stream
+    from repro.kernels.policy import KernelPolicy
+    s = lambda shape, dt: _spec(one_chip, shape, dt)    # noqa: E731
+    m_local, n_local = -(-NETFLIX_M // P), 19_912 // P
+    policy = KernelPolicy(impl="xla")
+    assert nomad._takes_kernel("tpu", None, policy, jnp.float32, P,
+                               n_local, K)
+    # the epoch body asks the backend, which is the CPU here
+    monkeypatch.setattr(nomad.kops, "on_tpu", lambda: True)
+    n, n_test = (1 << 16) * P + 5, 4096
+    data = (s((n,), jnp.int32), s((n,), jnp.int32), s((n,), jnp.float32),
+            s((n,), jnp.int32))
+    lowered = nomad._local_train_kernel.lower(
+        s((P, m_local, K), jnp.float32), s((P, n_local, K), jnp.float32),
+        data, s((2,), jnp.float32), s((2,), jnp.int32), 0.05,
+        s((n_test,), jnp.int32), s((n_test,), jnp.int32),
+        s((n_test,), jnp.float32), policy=policy, entry=None, n_rec=2)
+    text = _compile_fits(lowered, kernel=True).as_text()
+    assert "nomad_stream_epoch" in text
+    need = nomad_stream.vmem_bytes(P * n_local, K, P,
+                                   nomad_stream.chunk_slots(P))
+    assert 10 << 20 < need <= nomad_stream.VMEM_BUDGET
+
+
 @pytest.mark.parametrize("users", [1, 64])
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
 def test_topk_scorer_compiles_over_netflix_catalog(one_chip, impl, users):
